@@ -1,8 +1,7 @@
 #!/usr/bin/env bash
 # Tier-1 CPU verification — the exact command ROADMAP.md names.
-# Pallas kernels run under interpret=True on CPU (bit-exact vs oracles);
-# the hypothesis shim in tests/conftest.py keeps the property tests
-# collectable without the dependency.
+# Off the TPU the kernel wrappers take their bit-identical jnp
+# references; kernel parity tests run Pallas with interpret=True.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q "$@"

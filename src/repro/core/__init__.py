@@ -1,13 +1,7 @@
 """Origami core: blinding, Slalom protocol, precompute, executor, trust,
-partition planner."""
-from repro.core.blinding import BlindingSpec
-from repro.core.origami import MODES, OrigamiExecutor, OrigamiResult
-from repro.core.planner import PartitionPlan, PartitionPlanner
-from repro.core.precompute import BlindedLayerCache
-from repro.core.slalom import SlalomContext, Telemetry, blinded_dense
-from repro.core.trust import EnclaveParams, EnclaveSim
+partition planner.
 
-__all__ = ["BlindingSpec", "BlindedLayerCache", "MODES", "OrigamiExecutor",
-           "OrigamiResult", "PartitionPlan", "PartitionPlanner",
-           "SlalomContext", "Telemetry", "blinded_dense",
-           "EnclaveParams", "EnclaveSim"]
+Import the submodules directly (``from repro.core import plan as PL``,
+``from repro.core.origami import OrigamiExecutor``). The package itself
+imports nothing, so the kernel wrappers can import ``repro.core.tracing``
+without pulling in ``blinding.py``, which imports them back."""

@@ -12,6 +12,7 @@ one-time pad. Verified distributionally in tests/test_blinding.py.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -41,6 +42,15 @@ def blinding_stream(key: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
     return jax.random.randint(key, shape, 0, P, dtype=jnp.int32)
 
 
+@functools.partial(jax.jit, static_argnames=("k_w",))
+def _quantize_weight(w: jax.Array, k_w: int):
+    wf = w.astype(jnp.float32)
+    scale = jnp.maximum(jnp.max(jnp.abs(wf)), 1e-9)
+    q = jnp.clip(jnp.round(wf / scale * (2.0 ** k_w)),
+                 -HALF, HALF).astype(jnp.int32)
+    return from_signed(q), scale
+
+
 def quantize_weight(w: jax.Array, spec: BlindingSpec):
     """float weight -> (field representation, absmax scale).
 
@@ -48,12 +58,16 @@ def quantize_weight(w: jax.Array, spec: BlindingSpec):
     quantized integers use the full 2^k_w range regardless of weight
     magnitude. Returns (W_q in [0,p), scale) with
     W ≈ signed(W_q) · scale · 2^-k_w.
+
+    A concrete weight is quantized by this one compiled program even while
+    an enclosing trace is being built: the division is not correctly
+    rounded on every backend (the TPU divides by reciprocal, XLA may fold
+    constants on the host), so quantizing inside each caller's program
+    gave the trusted recompute and the precompute cache different
+    integers. Only traced weights (scanned blocks) quantize in-program.
     """
-    wf = w.astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(wf)), 1e-9)
-    q = jnp.clip(jnp.round(wf / scale * (2.0 ** spec.k_w)),
-                 -HALF, HALF).astype(jnp.int32)
-    return from_signed(q), scale
+    with jax.ensure_compile_time_eval():
+        return _quantize_weight(w, spec.k_w)
 
 
 def unblinding_factor(r: jax.Array, w_q: jax.Array) -> jax.Array:

@@ -48,6 +48,12 @@ from repro.runtime import aot as AOT
 
 MODES = PL.LEGACY_MODES
 
+# XLA may keep a bfloat16 intermediate in float32 where a fusion allows it,
+# so rounding would depend on the program around an op: the private and
+# trusted traces of a bf16 model then part by an ulp. Every AOT executable
+# rounds as the trace is written.
+COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
 
 @dataclass
 class OrigamiResult:
@@ -124,23 +130,18 @@ class OrigamiExecutor:
         self._tele_last = SL.Telemetry()
         self._tele_blinded = SL.Telemetry()
         self._tele_trusted = SL.Telemetry()
-        self._jitted = jax.jit(self._traced)
+        # AOT serving path: executables compiled explicitly (lower+compile)
+        # through a CompileCache (runtime/aot.py) instead of first-call jit.
+        # Nothing is donated: no output has the shape of a session factor,
+        # so XLA could alias none of them (on the TPU it only warned), and
+        # the factors carry the cache's shared weight planes, which must
+        # outlive every call. The §9 ladder also re-feeds the same batch
+        # to the retry and enclave-recompute executables. Every traced
+        # function takes the weights as its first argument (``_run``).
+        self._aot_jit = jax.jit(self._traced)
         # the recovery path: same math with the field matmuls run inside
         # the enclave (no device, no blinding, no injector) — bit-identical
         # logits, used after a failed Freivalds check or under quarantine
-        self._jitted_trusted = jax.jit(
-            functools.partial(self._traced, trusted=True))
-        # AOT serving path: executables compiled explicitly (lower+compile)
-        # through a CompileCache (runtime/aot.py) instead of first-call jit.
-        # The session factors buffer is donated off-CPU — it is per-session
-        # material the cache hands over exactly once (take()), never reused
-        # after the call. The *batch* is deliberately NOT donated: the §9
-        # integrity ladder re-feeds the same batch to the retry and
-        # enclave-recompute executables after a failed verify, and a donated
-        # input would already be dead by then. (CPU donation is unimplemented
-        # in XLA and only warns, but gating keeps the logs clean.)
-        donate = () if jax.default_backend() == "cpu" else (2,)
-        self._aot_jit = jax.jit(self._traced, donate_argnums=donate)
         self._aot_jit_trusted = jax.jit(
             functools.partial(self._traced, trusted=True))
         self._aot: AOT.CompileCache = AOT.CompileCache(None)  # memo-only
@@ -179,7 +180,8 @@ class OrigamiExecutor:
         return self.plan.n_layers
 
     # -- traced computation --------------------------------------------------
-    def _traced(self, batch, session_key, factors=None, trusted=False):
+    def _traced(self, params, batch, session_key, factors=None,
+                trusted=False):
         tele = SL.Telemetry()
         ctx = SL.SlalomContext(
             session_key, self.spec, telemetry=tele,
@@ -187,7 +189,7 @@ class OrigamiExecutor:
             integrity=IG.IntegrityPolicy.off(),  # set per plan segment
             fault=None if trusted else self.fault, trusted=trusted,
             plane=self.plane if self._plane_live and not trusted else None)
-        logits, boundary = self._run(batch, ctx)
+        logits, boundary = self._run(params, batch, ctx)
         if ctx.integrity_log:
             rep = tuple(jnp.stack([entry[i] for entry in ctx.integrity_log])
                         for i in range(3))
@@ -202,10 +204,19 @@ class OrigamiExecutor:
             self._tele_blinded = tele
         return logits, boundary, rep
 
-    def _run(self, batch, ctx):
+    def _run(self, params, batch, ctx):
         """Walk the plan segments — the ONE interpreter for all families
-        and all placements (no mode strings, no family forks)."""
-        params, prog, plan = self.params, self._program, self.plan
+        and all placements (no mode strings, no family forks).
+
+        ``params`` (a jit argument under the AOT path) feeds the open
+        segments, the prologue and the epilogue, so an executable does not
+        embed those weights as constants: compiling with VGG-16's 0.55 GB
+        embedded took 39.4 s on a TPU v5e, 4.9 s with it as an argument.
+        Offloaded segments read ``self.params``, concrete at trace time:
+        each blinded op then quantizes its weight exactly as the precompute
+        cache does (core/blinding.py:quantize_weight), and stays
+        individually addressable."""
+        prog, plan = self._program, self.plan
         x, memory = prog.prologue(params, batch)
         # span per plan segment — EAGER traces only (the pooled plane path
         # and recovery paths): under jit the walk runs once at trace time,
@@ -230,7 +241,8 @@ class OrigamiExecutor:
                         if prog.blind_convs:
                             stack.enter_context(L.conv_impl(
                                 functools.partial(SL.blinded_conv2d, ctx)))
-                        x = prog.segment(params, x, seg.lo, seg.hi, memory)
+                        x = prog.segment(self.params, x, seg.lo, seg.hi,
+                                         memory)
             if seg.hi == plan.boundary:
                 boundary = x
         return prog.epilogue(params, x, batch, memory), boundary
@@ -323,8 +335,8 @@ class OrigamiExecutor:
                 rec["policy"] = pol
         return records
 
-    def _traced_decode(self, token, caches, pos, session_key, factors=None,
-                       trusted: bool = False):
+    def _traced_decode(self, params, token, caches, pos, session_key,
+                       factors=None, trusted: bool = False):
         """ONE token step under the decode plan's scan segments.
 
         ``ctx.step`` is set to the TRACED position, so a single compiled
@@ -335,14 +347,15 @@ class OrigamiExecutor:
         derivation. ``per_op=True`` overrides the scanned-weight inference
         in core/slalom.py: the block walk is unrolled at trace time, so
         each traced dense call stands for exactly one runtime op and
-        verification/injection bind per (token, layer)."""
+        verification/injection bind per (token, layer). ``params`` feeds
+        the open segments, as in ``_run``."""
         tele = SL.Telemetry()
         ctx = SL.SlalomContext(
             session_key, self.spec, telemetry=tele, impl=self.impl,
             factors=factors, integrity=IG.IntegrityPolicy.off(),
             fault=None if trusted else self.fault, trusted=trusted,
             step=pos, per_op=True)
-        params, cfg = self.params, self.cfg
+        cfg = self.cfg
         x = M.embed_tokens_at(params, token, pos, cfg)
         for seg in self.dplan.scan:
             if seg.regime == "plain":
@@ -358,7 +371,7 @@ class OrigamiExecutor:
                 stack.enter_context(L.dense_impl(
                     functools.partial(SL.blinded_dense, ctx)))
                 x, caches = M.decode_range_unrolled(
-                    params, x, caches, pos, cfg, seg.lo, seg.hi)
+                    self.params, x, caches, pos, cfg, seg.lo, seg.hi)
         logits = M.head(params, x, cfg)
         rep = self._fold_log(ctx)
         if trusted:
@@ -367,8 +380,8 @@ class OrigamiExecutor:
             self._tele_blinded = tele
         return logits, caches, rep
 
-    def _traced_prefill(self, tokens, session_key, trusted: bool = False,
-                        *, max_seq: int):
+    def _traced_prefill(self, params, tokens, session_key,
+                        trusted: bool = False, *, max_seq: int):
         """Prompt pass through the BASE plan's segments, returning
         ``(last-position logits, decode caches, integrity log)``.
 
@@ -377,14 +390,15 @@ class OrigamiExecutor:
         blinding key and Freivalds fold (no cross-layer pad sharing) —
         while plain segments keep the scanned fast path. Prefill ops use
         ``step=0``; decode steps use ``step=pos >= 1`` (positions count
-        from the prompt length), so the two key domains never collide."""
+        from the prompt length), so the two key domains never collide.
+        ``params`` feeds the open segments, as in ``_run``."""
         tele = SL.Telemetry()
         ctx = SL.SlalomContext(
             session_key, self.spec, telemetry=tele, impl=self.impl,
             factors=None, integrity=IG.IntegrityPolicy.off(),
             fault=None if trusted else self.fault, trusted=trusted,
             step=0, per_op=True)
-        params, cfg = self.params, self.cfg
+        cfg = self.cfg
         x = M.embed_tokens(params, tokens, cfg)
         parts = []
         for seg in self.plan.segments:
@@ -399,7 +413,7 @@ class OrigamiExecutor:
                         shard=seg.shard))
                     stack.enter_context(L.dense_impl(
                         functools.partial(SL.blinded_dense, ctx)))
-                    x, c = M.prefill_range_unrolled(params, x, cfg,
+                    x, c = M.prefill_range_unrolled(self.params, x, cfg,
                                                     seg.lo, seg.hi)
             parts.append(c)
         caches = M.concat_layer_caches(parts, max_seq)
@@ -436,7 +450,7 @@ class OrigamiExecutor:
 
         def build():
             with tracing.maybe_span("compile.aot", "compile", trace=kind):
-                return jfn.lower(*args, **kw).compile()
+                return jfn.lower(*args, **kw).compile(COMPILER_OPTIONS)
 
         def replay_telemetry():
             with tracing.maybe_span("compile.aot", "compile", trace=kind,
@@ -448,23 +462,13 @@ class OrigamiExecutor:
         self._executables = {**self._executables, sig: compiled}
         return compiled
 
-    def _call_decode_exec(self, sig, compiled, jfn, args, kw):
-        try:
-            return compiled(*args)
-        except Exception:  # noqa: BLE001 — same contract as
-            # _call_executable: evict + fall back to the implicit-jit path
-            self._aot.record_fallback()
-            self._executables = {k: v for k, v in self._executables.items()
-                                 if k != sig}
-            return jfn(*args, **kw)
-
     def prefill_session(self, tokens, session_key, *, max_seq: int,
                         trusted: bool = False, jit: bool = True):
         """Public prompt pass: (logits at the last position, decode caches
         padded to ``max_seq``, IntegrityReport over the prefill ops)."""
         assert self.dplan is not None, "attach_decode_plan first"
         kw = {"trusted": trusted, "max_seq": int(max_seq)}
-        args = (tokens, session_key)
+        args = (self.params, tokens, session_key)
         if jit:
             sig = ("prefill", bool(trusted), self.dplan.digest,
                    tuple(tokens.shape), int(max_seq))
@@ -472,8 +476,7 @@ class OrigamiExecutor:
                 sig, self._jit_prefill, self._traced_prefill,
                 f"prefill{int(max_seq)}" + ("_trusted" if trusted else ""),
                 args, kw)
-            logits, caches, rep = self._call_decode_exec(
-                sig, ex, self._jit_prefill, args, kw)
+            logits, caches, rep = ex(*args)
         else:
             logits, caches, rep = self._traced_prefill(*args, **kw)
         self._tele_last = (self._tele_trusted if trusted
@@ -489,7 +492,7 @@ class OrigamiExecutor:
         assert self.dplan is not None, "attach_decode_plan first"
         pos = jnp.asarray(pos, jnp.int32)
         kw = {"trusted": trusted}
-        args = (token, caches, pos, session_key, factors)
+        args = (self.params, token, caches, pos, session_key, factors)
         if jit:
             sig = ("decode", bool(trusted), self.dplan.digest,
                    tuple(token.shape), self._cache_seq(caches),
@@ -497,8 +500,7 @@ class OrigamiExecutor:
             ex = self._ensure_decode_exec(
                 sig, self._jit_decode, self._traced_decode,
                 "decode" + ("_trusted" if trusted else ""), args, kw)
-            logits, caches, rep = self._call_decode_exec(
-                sig, ex, self._jit_decode, args, kw)
+            logits, caches, rep = ex(*args)
         else:
             logits, caches, rep = self._traced_decode(*args, **kw)
         self._tele_last = (self._tele_trusted if trusted
@@ -525,7 +527,7 @@ class OrigamiExecutor:
                     sig, self._jit_prefill, self._traced_prefill,
                     f"prefill{int(max_seq)}"
                     + ("_trusted" if trusted else ""),
-                    (tokens, key0),
+                    (self.params, tokens, key0),
                     {"trusted": trusted, "max_seq": int(max_seq)})
                 n += 1
                 factors = (None if trusted or cache is None
@@ -535,7 +537,8 @@ class OrigamiExecutor:
                 self._ensure_decode_exec(
                     sig, self._jit_decode, self._traced_decode,
                     "decode" + ("_trusted" if trusted else ""),
-                    (token, caches, jnp.int32(prompt_len), key0, factors),
+                    (self.params, token, caches, jnp.int32(prompt_len),
+                     key0, factors),
                     {"trusted": trusted})
                 n += 1
         return n
@@ -621,13 +624,13 @@ class OrigamiExecutor:
             return compiled
         kind = "trusted" if trusted else "blinded"
         jfn = self._aot_jit_trusted if trusted else self._aot_jit
-        args = (batch, session_key, factors)
+        args = (self.params, batch, session_key, factors)
         ck = self._aot.entry_key(self.plan.digest, kind, args)
 
         def build():
             with tracing.maybe_span("compile.aot", "compile",
                                     trusted=int(trusted)):
-                return jfn.lower(*args).compile()
+                return jfn.lower(*args).compile(COMPILER_OPTIONS)
 
         def replay_telemetry():
             # a deserialized executable never runs _traced, so the
@@ -644,19 +647,6 @@ class OrigamiExecutor:
         # serve (device-stage) threads
         self._executables = {**self._executables, sig: compiled}
         return compiled
-
-    def _call_executable(self, sig, compiled, args, trusted: bool):
-        try:
-            return compiled(*args)
-        except Exception:  # noqa: BLE001 — e.g. a disk-loaded executable
-            # incompatible at call time (runtime/toolchain drift the key
-            # did not capture): fall back to the plain jit path and evict,
-            # never fail the request
-            self._aot.record_fallback()
-            self._executables = {k: v for k, v in self._executables.items()
-                                 if k != sig}
-            fn = self._jitted_trusted if trusted else self._jitted
-            return fn(*args)
 
     def warm_aot(self, input_key: str, request_shape, buckets,
                  dtype=None, trusted_too: bool = True) -> int:
@@ -711,8 +701,7 @@ class OrigamiExecutor:
         shard_report = None
         if trusted:
             ex = self._ensure_executable(sig, batch, key, None, True)
-            logits, boundary, rep = self._call_executable(
-                sig, ex, (batch, key, None), True)
+            logits, boundary, rep = ex(self.params, batch, key, None)
         else:
             factors = self._session_factors(batch, key)
             # the plane's host-side dispatch (retry, hedging, per-device
@@ -723,15 +712,16 @@ class OrigamiExecutor:
             # regime the cross-checking drills run in
             if self._plane_live:
                 self.plane.begin_infer()
-                logits, boundary, rep = self._traced(batch, key, factors)
+                logits, boundary, rep = self._traced(self.params, batch, key,
+                                                     factors)
                 shard_report = self.plane.report
             elif jit:
                 ex = self._ensure_executable(sig, batch, key, factors,
                                              False)
-                logits, boundary, rep = self._call_executable(
-                    sig, ex, (batch, key, factors), False)
+                logits, boundary, rep = ex(self.params, batch, key, factors)
             else:
-                logits, boundary, rep = self._traced(batch, key, factors)
+                logits, boundary, rep = self._traced(self.params, batch, key,
+                                                     factors)
         # the jit cache may skip re-tracing; point the public snapshot at
         # the last trace of THIS kind so a recovery trace never masquerades
         # as an offload trace (or vice versa)

@@ -419,11 +419,14 @@ def extract_patches(x, kh: int, kw: int, stride: int = 1):
     Returns (B·Ho·Wo, cin·kh·kw) with channel-major ordering (c, i, j) —
     pair with ``conv_weight_cols``. Replaces the kh·kw-times-materialized
     Python-loop im2col (which built kh·kw full-size slices and concatenated
-    them in HBM before blinding).
+    them in HBM before blinding). The patch conv runs at HIGHEST precision:
+    at the TPU's default it rounds every activation to bfloat16 before
+    quantization.
     """
     patches = jax.lax.conv_general_dilated_patches(
         x, (kh, kw), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
     return patches.reshape(-1, patches.shape[-1]), patches.shape[:3]
 
 
@@ -443,6 +446,10 @@ def blinded_conv2d(ctx: SlalomContext, p, x, stride: int = 1):
     w = p["w"]                                # (kh, kw, cin, cout)
     kh, kw, cin, cout = w.shape
     xcol, out_hw = extract_patches(x, kh, kw, stride)
-    y = blinded_dense(ctx, {"w": conv_weight_cols(w), "b": p["b"]}, xcol,
+    # a concrete weight stays concrete, so it quantizes exactly as the
+    # precompute cache's copy does (blinding.quantize_weight)
+    with jax.ensure_compile_time_eval():
+        w_cols = conv_weight_cols(w)
+    y = blinded_dense(ctx, {"w": w_cols, "b": p["b"]}, xcol,
                       scanned=isinstance(w, jax.core.Tracer))
     return y.reshape(out_hw + (cout,))

@@ -90,20 +90,18 @@ def _field_matmul_jit(x_field, w_field, *, impl: str = "auto",
     M, K = x_field.shape
     K2, N = w_field.shape
     assert K == K2
-    # auto: off-TPU the pure-jnp reference (f32-exact limb GEMMs for
-    # K ≤ 2^10) beats interpreted Pallas by orders of magnitude and is
-    # bit-identical — same policy _field_fold_jit has always used.
-    if impl == "ref" or (impl == "auto" and
-                         (not _on_tpu() or M * N * K <= 64 ** 3)):
+    # auto: the compiled kernel on TPU at every size; off-TPU the pure-jnp
+    # reference (f32-exact limb GEMMs for K ≤ 2^10), which beats
+    # interpreted Pallas by orders of magnitude and is bit-identical
+    if impl == "ref" or (impl == "auto" and not _on_tpu()):
         return ref.field_matmul_ref(x_field, w_field)
     bm_, bn_, bk_, _, _, _ = block_plan(M, K, N, bm=bm, bn=bn, bk=bk)
     xl = jnp.moveaxis(ref.to_limbs(ref.to_signed(x_field)), -1, 0)  # (3,M,K)
     wl = jnp.moveaxis(ref.to_limbs(ref.to_signed(w_field)), -1, 0)  # (3,K,N)
     xl = _pad_to(_pad_to(xl, bm_, 1), bk_, 2)
     wl = _pad_to(_pad_to(wl, bk_, 1), bn_, 2)
-    out = limb_matmul_planes(
-        xl, wl, bm=bm_, bn=bn_, bk=bk_,
-        interpret=(impl == "interpret") or (impl == "auto" and not _on_tpu()))
+    out = limb_matmul_planes(xl, wl, bm=bm_, bn=bn_, bk=bk_,
+                             interpret=(impl == "interpret"))
     return out[:M, :N]
 
 
@@ -131,9 +129,8 @@ def _fused_blinded_matmul_jit(x, r, w_limbs, u, inv_scale, out_scale, *,
     assert w_limbs.shape == (3, Kp, Np), (w_limbs.shape, (3, Kp, Np))
     inv2 = jnp.asarray(inv_scale, jnp.float32).reshape(1, 1)
     sc2 = jnp.asarray(out_scale, jnp.float32).reshape(1, 1)
-    if impl == "ref" or (impl == "auto" and
-                         (not _on_tpu() or M * N * K <= 64 ** 3)):
-        # pure-jnp fallback, same op order as the kernels (bit-exact);
+    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+        # pure-jnp path, same op order as the kernels (bit-exact);
         # selected off-TPU like _field_matmul_jit / _field_fold_jit
         # because interpreted Pallas pays per-element python dispatch
         from repro.kernels.blind.ref import blind_ref
@@ -143,7 +140,7 @@ def _fused_blinded_matmul_jit(x, r, w_limbs, u, inv_scale, out_scale, *,
         y_b = ref.field_matmul_ref(blind_ref(xs, r, k_bits), w_f)
         s = ref.to_signed(ref.field_sub(y_b, u))
         return (s.astype(jnp.float32) * sc2[0, 0]).astype(out_dtype)
-    interpret = (impl == "interpret") or (impl == "auto" and not _on_tpu())
+    interpret = impl == "interpret"
     if interpret and Kp > K:
         # interpret mode pays per-element python dispatch, so K-padding is
         # real work (compiled TPU lanes make it free): encode at natural K,

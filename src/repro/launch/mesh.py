@@ -10,17 +10,11 @@ import jax
 from repro.configs.base import MeshConfig
 
 
-def _axis_types_kwargs(n: int) -> dict:
-    """jax.sharding.AxisType only exists on newer jax; older versions get
-    Auto semantics by default, so omitting the kwarg is equivalent."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_from_config(mesh_cfg: MeshConfig):
@@ -33,4 +27,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

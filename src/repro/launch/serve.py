@@ -46,6 +46,7 @@ from repro.core import plan as PL
 from repro.core.integrity import IntegrityPolicy
 from repro.models import model as M
 from repro.privacy.data import make_batch
+from repro.runtime.aot import use_persistent_compile_cache
 from repro.runtime.faults import DishonestDevice, FaultSpec
 from repro.runtime.serving import PrivateInferenceServer, Request
 
@@ -646,6 +647,7 @@ def main():
         ap.error("--metrics-out/--postmortem-dir require --engine")
     if (args.compile_cache_dir or args.aot_warm) and not args.engine:
         ap.error("--compile-cache-dir/--aot-warm require --engine")
+    use_persistent_compile_cache()
 
     if args.requests is None:
         args.requests = 32 if args.engine else 16

@@ -505,6 +505,15 @@ def decode_range(params, x, caches, pos, cfg: ModelConfig,
     return x, merged
 
 
+def _block_params(params, i: int):
+    """Block ``i``'s weights, sliced from the stack. Concrete params give
+    concrete slices even under an enclosing trace, so a blinded op's weight
+    quantizes exactly as the precompute cache's copy does
+    (core/blinding.py:quantize_weight)."""
+    with jax.ensure_compile_time_eval():
+        return jax.tree.map(lambda t: t[i], params["blocks"])
+
+
 def decode_range_unrolled(params, x, caches, pos, cfg: ModelConfig,
                           lo: int, hi: int):
     """``decode_range`` with the block walk UNROLLED at trace time
@@ -520,7 +529,7 @@ def decode_range_unrolled(params, x, caches, pos, cfg: ModelConfig,
     for plain segments and open generation."""
     new = []
     for i in range(lo, hi):
-        p_i = jax.tree.map(lambda t: t[i], params["blocks"])
+        p_i = _block_params(params, i)
         c_i = jax.tree.map(lambda c: None if c is None else c[i], caches,
                            is_leaf=lambda v: v is None)
         x, c_new = T.decoder_block_decode(p_i, x, c_i, pos, cfg)
@@ -562,7 +571,7 @@ def prefill_range_unrolled(params, x, cfg: ModelConfig, lo: int, hi: int, *,
     one scanned call (and one pad) across layers."""
     cs = []
     for i in range(lo, hi):
-        p_i = jax.tree.map(lambda t: t[i], params["blocks"])
+        p_i = _block_params(params, i)
         x, cache, _aux = T.decoder_block_prefill(p_i, x, cfg,
                                                  cost_mode=cost_mode)
         cs.append(cache)

@@ -315,13 +315,19 @@ class OffloadPlane:
                                   cancel=slot.cancel)
         x = task.x
         if slot.jax_device is not None:
+            # both operands go to the slot's device, and the product comes
+            # back to the enclave's device, where it is verified and joined
+            home = next(iter(x.devices()))
             x = jax.device_put(x, slot.jax_device)
+            w_q = jax.device_put(w_q, slot.jax_device)
         y = self._matmul(x, w_q)
         if slot.fault is not None:
             y, _ = slot.fault.corrupt(y, op_index=task.op_index,
                                       key=task.fault_key,
                                       will_verify=jnp.bool_(True))
         y = jax.block_until_ready(y)
+        if slot.jax_device is not None:
+            y = jax.block_until_ready(jax.device_put(y, home))
         if slot.sim_gflops:
             flops = 2 * x.shape[0] * x.shape[1] * y.shape[1]
             time.sleep(flops / (slot.sim_gflops * 1e9))

@@ -48,10 +48,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
-try:  # the serializer moved between jax versions; degrade to memo-only
-    from jax.experimental import serialize_executable as _sx
-except Exception:  # pragma: no cover - depends on jax build
-    _sx = None
+from jax.experimental import serialize_executable as _sx
 
 _PAYLOAD_VERSION = 1
 
@@ -62,6 +59,23 @@ _CODE_ROOTS = ("core", "kernels", "models")
 
 _code_version_cache: Optional[str] = None
 _code_version_lock = threading.Lock()
+
+# src/repro/runtime/aot.py -> the checkout root
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_persistent_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    used as it stands; otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``, so a later run finds what an earlier one
+    compiled. Returns the directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def code_version() -> str:
@@ -118,7 +132,7 @@ class CompileCache:
         # testable) without an engine attached
         self.counters: Dict[str, int] = {
             "compiles": 0, "memo_hits": 0, "disk_hits": 0,
-            "disk_errors": 0, "stores": 0, "exec_fallbacks": 0}
+            "disk_errors": 0, "stores": 0}
         self.compile_seconds = 0.0
         self.request_compile_seconds = 0.0
         # registration-time warmups flip this on so compile seconds are
@@ -175,11 +189,6 @@ class CompileCache:
             self.registry.gauge("aot.request_compile_seconds",
                                 self.request_compile_seconds)
 
-    def record_fallback(self) -> None:
-        """An AOT executable raised at call time and the executor fell
-        back to the implicit-jit path — count it (``aot.exec_fallbacks``)."""
-        self._bump("exec_fallbacks")
-
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             out: Dict[str, Any] = dict(self.counters)
@@ -197,7 +206,7 @@ class CompileCache:
 
     def _disk_load(self, key: str) -> Optional[Any]:
         path = self._path(key)
-        if path is None or _sx is None or not path.exists():
+        if path is None or not path.exists():
             return None
         try:
             with open(path, "rb") as fh:
@@ -217,7 +226,7 @@ class CompileCache:
 
     def _disk_store(self, key: str, compiled: Any) -> None:
         path = self._path(key)
-        if path is None or _sx is None:
+        if path is None:
             return
         try:
             payload, in_tree, out_tree = _sx.serialize(compiled)

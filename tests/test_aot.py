@@ -33,6 +33,33 @@ def _request(cfg, rid, rng):
 # pure pieces: the bucket ladder and the cache key
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("env_dir", [None, "jaxcache-from-env"])
+def test_persistent_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is used as it stands and the
+    helper sets no other directory; unset, the cache lives at the fixed
+    <checkout>/.jax_cache — never a temp-, pid- or time-derived path."""
+    from pathlib import Path
+    from repro.runtime.aot import use_persistent_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        path = use_persistent_compile_cache()
+        if env_dir is None:
+            checkout = Path(__file__).resolve().parents[1]
+            assert path == str(checkout / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert use_persistent_compile_cache() == path
+        else:
+            assert path == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_bucket_ladder_powers_of_two():
     assert bucket_ladder(4) == (1, 2, 4)
     assert bucket_ladder(8) == (1, 2, 4, 8)

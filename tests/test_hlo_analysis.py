@@ -18,8 +18,8 @@ SCAN_PROG = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel.hlo_analysis import analyze_hlo
-from repro.launch.mesh import _axis_types_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_axis_types_kwargs(2))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(2, 4)
 D, L, B = 128, 6, 64
 def f(x, ws):
     def body(c, w):
