@@ -82,7 +82,7 @@ def run_suite(emit: Callable[[str, float, str], None]) -> Dict[str, Dict]:
 
     cfg = get_smoke("vgg16")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
-    tracer = Tracer(kernel_spans=False)
+    tracer = Tracer()
     engine = ServingEngine(EngineConfig(max_batch=REQS_PER_ROUND,
                                         max_wait_ms=10.0), tracer=tracer)
     entry = engine.register_model("vgg16", cfg, params, mode="origami")

@@ -29,7 +29,6 @@ BENCH_CONFIG = {
     "plan": "mixed",
     "rounds": ROUNDS,
     "requests_per_round": REQS_PER_ROUND,
-    "kernel_spans": False,
     "threshold_pct": THRESHOLD_PCT,
 }
 
@@ -63,7 +62,7 @@ def _round(engine, cfg, rid0: int) -> float:
 def run_suite(emit: Callable[[str, float, str], None]) -> Dict[str, Dict]:
     from repro.core.tracing import Tracer
 
-    tracer = Tracer(kernel_spans=False)
+    tracer = Tracer()
     eng_off, cfg = _build_engine(None)
     eng_on, _ = _build_engine(tracer)
     try:

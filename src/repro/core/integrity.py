@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import blinding as B
+from repro.core import tracing
 from repro.kernels.limb_matmul.ops import field_fold
 
 # fold_in domain tags (distinct from any layer index / step used elsewhere)
@@ -161,14 +162,16 @@ def checked_pair(y_field: jax.Array, x_field: jax.Array, s: jax.Array,
 
     Returns (checked, failed) scalar bools. ``always`` (static) skips the
     lax.cond so "full" mode pays no branch; under "sampled" the cond means
-    a skipped op costs zero fold work at runtime.
+    a skipped op costs zero fold work at runtime. Traced under the
+    ``tier1.verify`` device phase.
     """
-    if always:
-        return jnp.bool_(True), ~fold_check(y_field, x_field, s, ws)
-    ok = jax.lax.cond(will_check,
-                      lambda: fold_check(y_field, x_field, s, ws),
-                      lambda: jnp.bool_(True))
-    return will_check, will_check & ~ok
+    with jax.named_scope(tracing.TIER1_VERIFY):
+        if always:
+            return jnp.bool_(True), ~fold_check(y_field, x_field, s, ws)
+        ok = jax.lax.cond(will_check,
+                          lambda: fold_check(y_field, x_field, s, ws),
+                          lambda: jnp.bool_(True))
+        return will_check, will_check & ~ok
 
 
 @dataclass

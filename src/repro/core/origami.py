@@ -228,7 +228,8 @@ class OrigamiExecutor:
                                      hi=seg.hi, regime=seg.regime)
                   if eager else nullcontext()):
                 if seg.regime == "plain":
-                    x = prog.segment(params, x, seg.lo, seg.hi, memory)
+                    with jax.named_scope(tracing.TIER2_OPEN):
+                        x = prog.segment(params, x, seg.lo, seg.hi, memory)
                 else:
                     policy = (seg.policy if seg.policy is not None
                               else self.integrity)
@@ -241,6 +242,11 @@ class OrigamiExecutor:
                         if prog.blind_convs:
                             stack.enter_context(L.conv_impl(
                                 functools.partial(SL.blinded_conv2d, ctx)))
+                        # the blinded ops scope their own phases; what is
+                        # left (activations, pooling) is the enclave's,
+                        # fused by XLA with the unblind before it
+                        stack.enter_context(
+                            jax.named_scope(tracing.TIER1_UNBLIND))
                         x = prog.segment(self.params, x, seg.lo, seg.hi,
                                          memory)
             if seg.hi == plan.boundary:
@@ -289,6 +295,7 @@ class OrigamiExecutor:
             cache = BlindedLayerCache.from_records(
                 self._decode_records(batch_size), self.spec,
                 integrity=self.integrity)
+            cache.bucket = batch_size
             # copy-on-write rebind: read by the ring's refill thread
             self._decode_caches = {**self._decode_caches,
                                    batch_size: cache}
@@ -570,6 +577,7 @@ class OrigamiExecutor:
                              else self.integrity)
         self.cache = BlindedLayerCache.from_records(records, self.spec,
                                                     integrity=self.integrity)
+        self.cache.bucket = batch_size
         if self._plane_live:
             # prefetch per-shard fold vectors alongside (r, u): the
             # SessionPool ring then keeps shard-local verification material

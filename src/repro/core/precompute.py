@@ -19,7 +19,11 @@ inside every traced request, so each "offloaded" matmul was paid twice
   generated off the request path. ``prefetch`` enqueues the next session's
   factors while the current batch runs on device (JAX async dispatch
   overlaps them — the double-buffering runtime/serving.py drives); ``take``
-  pops a prefetched set or falls back to computing synchronously.
+  pops a prefetched set or falls back to computing synchronously, and
+  counts both (``factors_taken``, ``factors_sync``; the fallback records a
+  ``factors.sync`` span). Each layer's set is one compiled program whose
+  ops run under the device phases ``factors.pad`` (r), ``factors.unblind``
+  (u = r·W_q) and ``factors.fold`` (s, W_q·s) of core/tracing.py.
 
 Factor keying is ``stream_key(session_key, layer_index, step)`` — exactly
 the stream the on-the-fly path draws, so cached and uncached traces are
@@ -35,6 +39,7 @@ active the skinny fold matmuls are off the request path too.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -44,6 +49,7 @@ import numpy as np
 
 from repro.core import blinding as B
 from repro.core import integrity as IG
+from repro.core import tracing
 from repro.kernels.limb_matmul.ops import encode_weight_planes, field_matmul
 
 
@@ -65,6 +71,37 @@ class CachedLayer:
     policy: Optional[IG.IntegrityPolicy] = None
 
 
+@functools.partial(jax.jit, static_argnames=("layer", "t", "d_in", "d_out",
+                                             "pad", "k", "shards"))
+def _layer_factors(session_key, step, w_q, *, layer: int, t: int, d_in: int,
+                   d_out: int, pad: bool, k: int, shards: int
+                   ) -> Dict[str, Any]:
+    """One layer's session factors as one program: the pad r and u = r·W_q
+    (``pad``), the fold vectors s and W_q·s (``k`` > 0), and ``shards``
+    per-shard fold pairs (``shards`` > 1, ``k`` columns, at least one).
+    The same stream keys and the same ops as the live derivation in
+    core/slalom.py, so the bits are the same."""
+    out: Dict[str, Any] = {}
+    if pad:
+        with jax.named_scope(tracing.FACTORS_PAD):
+            out["r"] = B.blinding_stream(B.stream_key(session_key, layer,
+                                                      step), (t, d_in))
+        with jax.named_scope(tracing.FACTORS_UNBLIND):
+            out["u"] = field_matmul(out["r"], w_q)
+    with jax.named_scope(tracing.FACTORS_FOLD):
+        if k:
+            out["s"] = IG.fold_stream(session_key, layer, step, d_out, k)
+            out["ws"] = field_matmul(w_q, out["s"])
+        if shards > 1:
+            folds = []
+            for j in range(shards):
+                s_j = IG.shard_fold_stream(session_key, layer, step, j,
+                                           d_out, max(k, 1))
+                folds.append((s_j, field_matmul(w_q, s_j)))
+            out["shard_folds"] = folds
+    return out
+
+
 class BlindedLayerCache:
     """Quantize-once weight cache + per-session blinding-factor store."""
 
@@ -81,6 +118,9 @@ class BlindedLayerCache:
         self.shards = 1
         self.factor_matmuls = 0          # r@W_q matmuls issued off-path
         self.fold_matmuls = 0            # W_q@s fold matmuls issued off-path
+        self.factors_taken = 0           # sets handed to a request (take)
+        self.factors_sync = 0            # ... of them generated by take itself
+        self.bucket: Optional[int] = None  # batch rows (set by the executor)
         self._ready: Dict[Tuple[bytes, int], List[Dict[str, Any]]] = {}
         # prefetch/take race under the serving engine: the SessionPool's
         # refill thread inserts while the batcher thread pops
@@ -125,41 +165,26 @@ class BlindedLayerCache:
         consumed positionally by SlalomContext."""
         factors = []
         for i, lyr in enumerate(self.layers):
-            if lyr.unblinded:
-                # verified-open slot: zero pad, u = (0 @ W) = 0 — nothing
-                # to matmul or store. The entry keeps its positional slot
-                # with r/u = None; the consumer synthesizes the zeros
-                # inside the trace (core/slalom.py), so a prefetch ring
-                # never pins full-size constant-zero arrays per session.
-                r = u = None
-            else:
-                key = B.stream_key(session_key, i, step)
-                r = B.blinding_stream(key, (lyr.t, lyr.d_in))
-                u = field_matmul(r, lyr.w_q)
-                self.factor_matmuls += 1
-            entry = {"r": r, "u": u, "w_q": lyr.w_q,
-                     "w_limbs": lyr.w_limbs, "w_scale": lyr.w_scale}
+            # verified-open slot (``unblinded``): zero pad, u = (0 @ W) = 0
+            # — nothing to matmul or store. The entry keeps its positional
+            # slot with r/u = None; the consumer synthesizes the zeros
+            # inside the trace (core/slalom.py), so a prefetch ring never
+            # pins full-size constant-zero arrays per session.
             pol = lyr.policy if lyr.policy is not None else self.integrity
-            if pol.enabled:
-                # same key derivation as the on-the-fly path in
-                # core/slalom.py — cached and live verification bit-match
-                entry["s"] = IG.fold_stream(session_key, i, step,
-                                            lyr.d_out, pol.k)
-                entry["ws"] = field_matmul(lyr.w_q, entry["s"])
-                self.fold_matmuls += 1
-            if self.shards > 1:
-                # per-shard fold vectors for the offload plane — shards are
-                # ALWAYS checked (k falls back to 1 with the policy off);
-                # derivation matches integrity.shard_fold_stream so cached
-                # and live shard verification are bit-identical
-                k = pol.k if pol.enabled else 1
-                folds = []
-                for j in range(self.shards):
-                    s_j = IG.shard_fold_stream(session_key, i, step, j,
-                                               lyr.d_out, k)
-                    folds.append((s_j, field_matmul(lyr.w_q, s_j)))
-                    self.fold_matmuls += 1
-                entry["shard_folds"] = folds
+            k = pol.k if pol.enabled else 0
+            # ``shards`` > 1: per-shard fold vectors for the offload plane
+            # — shards are ALWAYS checked (k falls back to 1 with the
+            # policy off); derivation matches integrity.shard_fold_stream
+            # so cached and live shard verification are bit-identical
+            entry = {"r": None, "u": None, "w_q": lyr.w_q,
+                     "w_limbs": lyr.w_limbs, "w_scale": lyr.w_scale}
+            entry.update(_layer_factors(
+                session_key, step, lyr.w_q, layer=i, t=lyr.t, d_in=lyr.d_in,
+                d_out=lyr.d_out, pad=not lyr.unblinded, k=k,
+                shards=self.shards))
+            self.factor_matmuls += not lyr.unblinded
+            self.fold_matmuls += bool(k) + (self.shards
+                                            if self.shards > 1 else 0)
             factors.append(entry)
         return factors
 
@@ -201,10 +226,18 @@ class BlindedLayerCache:
             self._ready.clear()
 
     def take(self, session_key, step: int = 0) -> List[Dict]:
-        """Pop prefetched factors for this session, or compute them now."""
+        """Pop prefetched factors for this session, or compute them now
+        (counted, and recorded as a ``factors.sync`` span: the request
+        waits for them)."""
         with self._lock:
             hit = self._ready.pop(self._skey(session_key, step), None)
-        return hit or self.session_factors(session_key, step)
+            self.factors_taken += 1
+            self.factors_sync += hit is None
+        if hit is not None:
+            return hit
+        with tracing.maybe_span("factors.sync", "session",
+                                layers=len(self.layers), bucket=self.bucket):
+            return self.session_factors(session_key, step)
 
     @property
     def num_layers(self) -> int:

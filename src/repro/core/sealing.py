@@ -17,7 +17,8 @@ the keystream derivation and the per-word MAC scan are pure integer ops
 whose eager dispatch used to cost ~100 ms per 3 K-word request — two
 orders of magnitude more than the compiled loop, for bit-identical words.
 Only the trust-boundary tag compare stays on the host (a Python bool from
-``hmac.compare_digest``), so the security posture is unchanged.
+``hmac.compare_digest``), so the security posture is unchanged. Each core
+runs under its device phase (core/tracing.py ``DEVICE_PHASES``).
 """
 from __future__ import annotations
 
@@ -27,6 +28,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core import tracing
 
 
 class SealedBox(NamedTuple):
@@ -72,26 +75,56 @@ def _authenticated_words(nonce: jax.Array, ct: jax.Array) -> jax.Array:
 @jax.jit
 def _seal_core(key: jax.Array, x: jax.Array,
                nonce: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    bits = jax.lax.bitcast_convert_type(
-        x.astype(jnp.float32), jnp.uint32).reshape(-1)
-    ks = _keystream(key, nonce, bits.size)
-    ct = bits ^ ks
-    return (ct.reshape(x.shape),
-            _mac(key, _authenticated_words(nonce, ct)))
+    with jax.named_scope(tracing.ENCLAVE_SEAL):
+        bits = jax.lax.bitcast_convert_type(
+            x.astype(jnp.float32), jnp.uint32).reshape(-1)
+        ks = _keystream(key, nonce, bits.size)
+        ct = bits ^ ks
+        return (ct.reshape(x.shape),
+                _mac(key, _authenticated_words(nonce, ct)))
+
+
+def _unseal_words(key: jax.Array, ct: jax.Array,
+                  nonce: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    ct_flat = ct.reshape(-1)
+    want = _mac(key, _authenticated_words(nonce, ct_flat))
+    ks = _keystream(key, nonce, ct_flat.size)
+    return (jax.lax.bitcast_convert_type(ct_flat ^ ks,
+                                         jnp.float32).reshape(ct.shape),
+            want)
+
+
+# one body, two programs: the enclave's unseal of a request and the
+# client's open of a response run the same words, MAC and compare, each
+# traced under its own device phase
+@jax.jit
+def _unseal_core(key: jax.Array, ct: jax.Array,
+                 nonce: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    with jax.named_scope(tracing.ENCLAVE_UNSEAL):
+        return _unseal_words(key, ct, nonce)
 
 
 @jax.jit
-def _unseal_core(key: jax.Array, ct_flat: jax.Array,
-                 nonce: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    want = _mac(key, _authenticated_words(nonce, ct_flat))
-    ks = _keystream(key, nonce, ct_flat.size)
-    return jax.lax.bitcast_convert_type(ct_flat ^ ks, jnp.float32), want
+def _open_core(key: jax.Array, ct: jax.Array,
+               nonce: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    with jax.named_scope(tracing.CLIENT_OPEN):
+        return _unseal_words(key, ct, nonce)
 
 
 def seal(key: jax.Array, x: jax.Array, nonce: jax.Array) -> SealedBox:
     """Encrypt + authenticate a float tensor under the session key."""
     ct, mac = _seal_core(key, jnp.asarray(x), jnp.asarray(nonce, jnp.uint32))
     return SealedBox(ciphertext=ct, nonce=nonce, mac=mac)
+
+
+def _open_box(core, key: jax.Array, box: SealedBox,
+              shape: Tuple[int, ...]) -> Tuple[jax.Array, bool]:
+    pt, want = core(jnp.asarray(key), jnp.asarray(box.ciphertext),
+                    jnp.asarray(box.nonce, jnp.uint32))
+    ok = hmac.compare_digest(
+        np.asarray(want, np.uint32).tobytes(),
+        np.asarray(box.mac, np.uint32).tobytes())
+    return pt.reshape(shape), ok
 
 
 def unseal(key: jax.Array, box: SealedBox,
@@ -102,10 +135,12 @@ def unseal(key: jax.Array, box: SealedBox,
     canonical little-endian uint32 encodings of the two tags — the accept
     decision itself is never traced; only the tag/keystream arithmetic is.
     """
-    pt, want = _unseal_core(jnp.asarray(key),
-                            jnp.asarray(box.ciphertext).reshape(-1),
-                            jnp.asarray(box.nonce, jnp.uint32))
-    ok = hmac.compare_digest(
-        np.asarray(want, np.uint32).tobytes(),
-        np.asarray(box.mac, np.uint32).tobytes())
-    return pt.reshape(shape), ok
+    return _open_box(_unseal_core, key, box, shape)
+
+
+def open_response(key: jax.Array, box: SealedBox,
+                  shape: Tuple[int, ...]) -> Tuple[jax.Array, bool]:
+    """``unseal`` for the client opening a response: the same words, MAC
+    and compare, run as the client's own program (device phase
+    ``client.open``)."""
+    return _open_box(_open_core, key, box, shape)

@@ -216,16 +216,19 @@ class SlalomContext:
         else:
             key = self.next_layer_key()
             w_q, w_scale = B.quantize_weight(w, self.spec)
-            r = B.blinding_stream(key, (t, d_in))
-            u = B.unblinding_factor(r, w_q)     # on-request (Slalom does this
-            self.telemetry.enclave_matmuls += 1  # offline; see precompute.py)
-            w_limbs = s = ws = None
+            with jax.named_scope(tracing.FACTORS_PAD):
+                r = B.blinding_stream(key, (t, d_in))
+            with jax.named_scope(tracing.FACTORS_UNBLIND):
+                u = B.unblinding_factor(r, w_q)  # on-request (Slalom does
+            self.telemetry.enclave_matmuls += 1  # this offline; see
+            w_limbs = s = ws = None              # precompute.py)
         if self.integrity.enabled and s is None:
             # same derivation as BlindedLayerCache.session_factors, so the
             # cached and live verification traces are bit-identical
-            s = IG.fold_stream(self.session_key, op, self.step,
-                               d_out, self.integrity.k)
-            ws = field_matmul(w_q, s)
+            with jax.named_scope(tracing.FACTORS_FOLD):
+                s = IG.fold_stream(self.session_key, op, self.step,
+                                   d_out, self.integrity.k)
+                ws = field_matmul(w_q, s)
             self.telemetry.fold_matmuls += 1    # on the request path — the
             self.telemetry.verify_flops += (    # cache moves these offline
                 2 * d_in * d_out * self.integrity.k)
@@ -277,17 +280,23 @@ def _blinded_dense(ctx: SlalomContext, p, x,
         # indistinguishable from an honest device's (tests/test_integrity).
         ctx._layer_counter += 1
         w_q, w_scale = B.quantize_weight(w, spec)
-        x_scale = jnp.maximum(jnp.max(jnp.abs(xt.astype(jnp.float32))), 1e-9)
-        # fused blinds with multiply-by-reciprocal, unfused with division —
-        # replicate the active impl so the recompute stays bit-identical
-        xs = (xt.astype(jnp.float32) * (1.0 / x_scale) if ctx.impl == "fused"
-              else xt.astype(jnp.float32) / x_scale)
-        y_field = field_matmul(from_signed(quantize_act(xs, spec.k_act)), w_q)
-        y = (to_signed(y_field).astype(jnp.float32)
-             * (x_scale * w_scale)) * (2.0 ** -k_out)
+        with jax.named_scope(tracing.TIER1_BLIND):
+            x_scale = jnp.maximum(jnp.max(jnp.abs(xt.astype(jnp.float32))),
+                                  1e-9)
+            # fused blinds with multiply-by-reciprocal, unfused with
+            # division — replicate the active impl so the recompute stays
+            # bit-identical
+            xs = (xt.astype(jnp.float32) * (1.0 / x_scale)
+                  if ctx.impl == "fused" else xt.astype(jnp.float32) / x_scale)
+            x_field = from_signed(quantize_act(xs, spec.k_act))
+        with jax.named_scope(tracing.TIER1_MATMUL):
+            y_field = field_matmul(x_field, w_q)
+        with jax.named_scope(tracing.TIER1_UNBLIND):
+            y = (to_signed(y_field).astype(jnp.float32)
+                 * (x_scale * w_scale)) * (2.0 ** -k_out)
+            if "b" in p:
+                y = y + p["b"].astype(jnp.float32)
         ctx.telemetry.record_trusted(t, d_in, d_out)
-        if "b" in p:
-            y = y + p["b"].astype(jnp.float32)
         return y.reshape(lead + (d_out,)).astype(x.dtype)
 
     # --- enclave: weight quantization + blinding material (precomputed when
@@ -307,7 +316,8 @@ def _blinded_dense(ctx: SlalomContext, p, x,
         else:
             scanned = isinstance(w, jax.core.Tracer)
     # --- enclave: per-request absmax activation scale ---
-    x_scale = jnp.maximum(jnp.max(jnp.abs(xt.astype(jnp.float32))), 1e-9)
+    with jax.named_scope(tracing.TIER1_BLIND):
+        x_scale = jnp.maximum(jnp.max(jnp.abs(xt.astype(jnp.float32))), 1e-9)
     if ctx.plane is not None and not scanned:
         # --- multi-device plane: the device matmul shards across the pool
         # (parallel/offload_sharding.py) with shard-local Freivalds checks,
@@ -318,35 +328,40 @@ def _blinded_dense(ctx: SlalomContext, p, x,
         # no *unrecovered* failure (the plane's ShardReport carries
         # detection/retry counts and the pool the per-device health).
         k_out = spec.k_act + spec.k_w
-        if ctx.impl == "fused":
-            # replicate the fused kernel's quantization exactly (multiply
-            # by reciprocal; kernels/blind/ref.py is the kernel oracle) so
-            # the sharded result is bit-identical to fused_blinded_matmul
-            xs = xt.astype(jnp.float32) * (1.0 / x_scale)
-        else:
-            xs = xt.astype(jnp.float32) / x_scale
-        x_b = jnp.mod(from_signed(quantize_act(xs, spec.k_act)) + r, P)
-        y_b = ctx.plane.matmul(
-            x_b, w_q, session_key=ctx.session_key, op_index=op_index,
-            step=ctx.step, k=ctx.integrity.k if ctx.integrity.enabled else 1,
-            folds=sf,
-            mode=ctx.shard.mode if ctx.shard is not None else None,
-            group=ctx.shard.devices if ctx.shard is not None else None)
-        if ctx.impl == "fused":
-            out_scale = x_scale * w_scale * (2.0 ** -k_out)
-            y = (to_signed(jnp.mod(y_b - u + P, P)).astype(jnp.float32)
-                 * out_scale)
-        else:
-            y = B.unblind_result(y_b, u, spec, out_dtype=jnp.float32)
-            y = y * (x_scale * w_scale)
+        with jax.named_scope(tracing.TIER1_BLIND):
+            if ctx.impl == "fused":
+                # replicate the fused kernel's quantization exactly
+                # (multiply by reciprocal; kernels/blind/ref.py is the
+                # kernel oracle) so the sharded result is bit-identical to
+                # fused_blinded_matmul
+                xs = xt.astype(jnp.float32) * (1.0 / x_scale)
+            else:
+                xs = xt.astype(jnp.float32) / x_scale
+            x_b = jnp.mod(from_signed(quantize_act(xs, spec.k_act)) + r, P)
+        with jax.named_scope(tracing.TIER1_MATMUL):
+            y_b = ctx.plane.matmul(
+                x_b, w_q, session_key=ctx.session_key, op_index=op_index,
+                step=ctx.step,
+                k=ctx.integrity.k if ctx.integrity.enabled else 1,
+                folds=sf,
+                mode=ctx.shard.mode if ctx.shard is not None else None,
+                group=ctx.shard.devices if ctx.shard is not None else None)
+        with jax.named_scope(tracing.TIER1_UNBLIND):
+            if ctx.impl == "fused":
+                out_scale = x_scale * w_scale * (2.0 ** -k_out)
+                y = (to_signed(jnp.mod(y_b - u + P, P)).astype(jnp.float32)
+                     * out_scale)
+            else:
+                y = B.unblind_result(y_b, u, spec, out_dtype=jnp.float32)
+                y = y * (x_scale * w_scale)
+            if "b" in p:
+                y = y + p["b"].astype(jnp.float32)
         ctx.integrity_log.append((jnp.bool_(True), jnp.bool_(False),
                                   jnp.bool_(False)))
         ctx.telemetry.record_verify(t, d_in, d_out,
                                     ctx.integrity.k
                                     if ctx.integrity.enabled else 1)
         ctx.telemetry.device_matmuls += 1
-        if "b" in p:
-            y = y + p["b"].astype(jnp.float32)
         ctx.telemetry.record_offload(t, d_in, d_out)
         return y.reshape(lead + (d_out,)).astype(x.dtype)
 
@@ -359,45 +374,56 @@ def _blinded_dense(ctx: SlalomContext, p, x,
         if w_limbs is None:
             w_limbs = encode_weight_planes(w_q)
         out_scale = x_scale * w_scale * (2.0 ** -k_out)
-        y = fused_blinded_matmul(
-            xt.astype(jnp.float32), r, w_limbs, u, 1.0 / x_scale, out_scale,
-            k_bits=spec.k_act, k_out_bits=k_out)
+        # one kernel chain (blind-encode pass, matmul, in-register unblind
+        # epilogue), one device phase; tier1.blind here is the absmax scale
+        with jax.named_scope(tracing.TIER1_MATMUL):
+            y = fused_blinded_matmul(
+                xt.astype(jnp.float32), r, w_limbs, u, 1.0 / x_scale,
+                out_scale, k_bits=spec.k_act, k_out_bits=k_out)
         if verify or inject:
-            # the fused kernel unblinds+dequantizes in-register; recover the
-            # signed field result exactly (|y_q| ≤ HALF < 2^22 and the only
-            # inexact step is one f32 multiply, so round() inverts it)
-            y_q = jnp.round(y / out_scale).astype(jnp.int32)
-            y_field = from_signed(y_q)
-            if inject:
-                y_field, corrupted = ctx.fault.corrupt(
-                    y_field, op_index=op_index, key=ctx.fault_key(op_index),
-                    will_verify=will_check)
-            if verify:
-                # post-unblind identity: y_q @ s ≡ x_q @ ws (mod p); x_q is
-                # the enclave's own quantization of its own activations
-                # (bit-identical to the kernel's: same reciprocal, same
-                # round/clip — kernels/blind/ref.py is the kernel oracle)
-                x_field = from_signed(quantize_act(
-                    xt.astype(jnp.float32) * (1.0 / x_scale), spec.k_act))
-                checked, failed = IG.checked_pair(
-                    y_field, x_field, s, ws, will_check,
-                    always=ctx.integrity.mode == "full")
-            y = to_signed(y_field).astype(jnp.float32) * out_scale
+            with jax.named_scope(tracing.TIER1_VERIFY):
+                # the fused kernel unblinds+dequantizes in-register; recover
+                # the signed field result exactly (|y_q| ≤ HALF < 2^22 and
+                # the only inexact step is one f32 multiply, so round()
+                # inverts it)
+                y_q = jnp.round(y / out_scale).astype(jnp.int32)
+                y_field = from_signed(y_q)
+                if inject:
+                    y_field, corrupted = ctx.fault.corrupt(
+                        y_field, op_index=op_index,
+                        key=ctx.fault_key(op_index), will_verify=will_check)
+                if verify:
+                    # post-unblind identity: y_q @ s ≡ x_q @ ws (mod p); x_q
+                    # is the enclave's own quantization of its own
+                    # activations (bit-identical to the kernel's: same
+                    # reciprocal, same round/clip — kernels/blind/ref.py is
+                    # the kernel oracle)
+                    x_field = from_signed(quantize_act(
+                        xt.astype(jnp.float32) * (1.0 / x_scale), spec.k_act))
+                    checked, failed = IG.checked_pair(
+                        y_field, x_field, s, ws, will_check,
+                        always=ctx.integrity.mode == "full")
+            with jax.named_scope(tracing.TIER1_UNBLIND):
+                y = to_signed(y_field).astype(jnp.float32) * out_scale
     else:
         # --- seed path: blind, device field-matmul, unblind (3 HBM trips) ---
-        x_b = B.blind_activations(xt.astype(jnp.float32) / x_scale, r, spec)
-        y_b = field_matmul(x_b, w_q)
-        if inject:
-            y_b, corrupted = ctx.fault.corrupt(
-                y_b, op_index=op_index, key=ctx.fault_key(op_index),
-                will_verify=will_check)
+        with jax.named_scope(tracing.TIER1_BLIND):
+            x_b = B.blind_activations(xt.astype(jnp.float32) / x_scale, r,
+                                      spec)
+        with jax.named_scope(tracing.TIER1_MATMUL):
+            y_b = field_matmul(x_b, w_q)
+            if inject:
+                y_b, corrupted = ctx.fault.corrupt(
+                    y_b, op_index=op_index, key=ctx.fault_key(op_index),
+                    will_verify=will_check)
         if verify:
             # blinded-domain identity: y_b @ s ≡ x_b @ ws (mod p)
             checked, failed = IG.checked_pair(
                 y_b, x_b, s, ws, will_check,
                 always=ctx.integrity.mode == "full")
-        y = B.unblind_result(y_b, u, spec, out_dtype=jnp.float32)
-        y = y * (x_scale * w_scale)
+        with jax.named_scope(tracing.TIER1_UNBLIND):
+            y = B.unblind_result(y_b, u, spec, out_dtype=jnp.float32)
+            y = y * (x_scale * w_scale)
     if verify or inject:
         false = jnp.bool_(False)
         ctx.integrity_log.append((
@@ -408,7 +434,8 @@ def _blinded_dense(ctx: SlalomContext, p, x,
             ctx.telemetry.record_verify(t, d_in, d_out, ctx.integrity.k)
     ctx.telemetry.device_matmuls += 1
     if "b" in p:
-        y = y + p["b"].astype(jnp.float32)
+        with jax.named_scope(tracing.TIER1_UNBLIND):
+            y = y + p["b"].astype(jnp.float32)
     ctx.telemetry.record_offload(t, d_in, d_out)
     return y.reshape(lead + (d_out,)).astype(x.dtype)
 
@@ -423,11 +450,12 @@ def extract_patches(x, kh: int, kw: int, stride: int = 1):
     at the TPU's default it rounds every activation to bfloat16 before
     quantization.
     """
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (kh, kw), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        precision=jax.lax.Precision.HIGHEST)
-    return patches.reshape(-1, patches.shape[-1]), patches.shape[:3]
+    with jax.named_scope(tracing.TIER1_PATCHES):
+        patches = jax.lax.conv_general_dilated_patches(
+            x, (kh, kw), (stride, stride), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=jax.lax.Precision.HIGHEST)
+        return patches.reshape(-1, patches.shape[-1]), patches.shape[:3]
 
 
 def conv_weight_cols(w):

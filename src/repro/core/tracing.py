@@ -6,9 +6,16 @@ unseal — and the ROADMAP's throughput work needs to *attribute* a request's
 latency across them, not guess. ``Tracer`` records a span tree per request:
 the engine opens a ``request`` root at submit, every downstream stage
 (runtime/serving.py, core/origami.py, core/slalom.py,
-parallel/offload_sharding.py, the kernel wrappers) attaches children via
-the ambient context, and the whole tree exports as Chrome-trace JSON
-(chrome://tracing / Perfetto) or JSONL.
+parallel/offload_sharding.py) attaches children via the ambient context,
+and the whole tree exports as Chrome-trace JSON (chrome://tracing /
+Perfetto) or JSONL.
+
+Spans are the host's view. What the device does inside one compiled
+program is named by ``DEVICE_PHASES``: each phase's traced code runs under
+a ``jax.named_scope`` of that name, which rides into the HLO as op
+metadata and so into the profiler's trace at no run-time cost.
+``Tracer.clock_anchor`` writes the tracer's own clock into the profiler's
+trace, so spans and device operations can be laid on one time axis.
 
 **Telemetry is a threat surface.** In a TEE deployment the trace file
 leaves the trust boundary (dashboards, CI artifacts), and Privado-style
@@ -49,7 +56,31 @@ _ids = itertools.count(1)          # CPython next() is atomic
 
 # span kinds (the taxonomy DESIGN.md §13 tabulates)
 KINDS = ("request", "queue", "batch", "session", "crypto", "infer",
-         "step", "shard", "verify", "kernel")
+         "step", "shard", "verify", "compile")
+
+# device phases: ``jax.named_scope`` names the program's traced code runs
+# under (DESIGN.md §13). A scope is HLO metadata only — it adds no device
+# work and changes no output bit — and it reaches only ops traced inside
+# it, so every phase below is traced inside some jit.
+ENCLAVE_UNSEAL = "enclave.unseal"   # request keystream + MAC (sealing.py)
+CLIENT_OPEN = "client.open"         # the client's response open, same body
+ENCLAVE_SEAL = "enclave.seal"       # response keystream + MAC
+TIER1_PATCHES = "tier1.patches"     # im2col of a blinded conv (slalom.py)
+TIER1_BLIND = "tier1.blind"         # absmax scale, quantize + blind-encode
+TIER1_MATMUL = "tier1.matmul"       # the device's field / fused limb matmul
+TIER1_UNBLIND = "tier1.unblind"     # unblind, dequantize, bias, and the
+                                    # enclave's activations and pooling
+TIER1_VERIFY = "tier1.verify"       # Freivalds fold check (integrity.py)
+TIER2_OPEN = "tier2.open"           # plain plan segments (origami.py)
+FACTORS_PAD = "factors.pad"         # session pads r (precompute.py)
+FACTORS_UNBLIND = "factors.unblind"  # u = r·W_q
+FACTORS_FOLD = "factors.fold"       # fold vectors s and W_q·s
+DEVICE_PHASES = (ENCLAVE_UNSEAL, CLIENT_OPEN, ENCLAVE_SEAL, TIER1_PATCHES,
+                 TIER1_BLIND, TIER1_MATMUL, TIER1_UNBLIND, TIER1_VERIFY,
+                 TIER2_OPEN, FACTORS_PAD, FACTORS_UNBLIND, FACTORS_FOLD)
+
+# the profiler annotation that carries the tracer's clock (clock_anchor)
+CLOCK_ANCHOR = "tracer.clock"
 
 _MAX_STR = 512                     # longest attribute string (digests fit)
 _MAX_ITEMS = 64                    # longest attribute list/dict
@@ -126,18 +157,19 @@ class Span:
 class Tracer:
     """Thread-safe bounded span store with redaction-enforced attributes.
 
-    ``kernel_spans`` gates the block_until_ready-fenced kernel hooks
-    (``profiled_kernel``) — the only instrumentation that *changes* device
-    scheduling (a fence serializes async dispatch), so it is opt-outable
-    independently of the request/stage spans.
+    ``kernel_spans`` is retired and ignored: device time per phase comes
+    from the profiler's trace (``DEVICE_PHASES``), not from host spans
+    fenced around each kernel. It is still accepted because benchmark
+    harnesses of earlier versions pass it.
     """
 
     MAX_SPANS = 200_000
 
-    def __init__(self, *, enabled: bool = True, kernel_spans: bool = True,
+    def __init__(self, *, enabled: bool = True,
+                 kernel_spans: Optional[bool] = None,
                  max_spans: int = MAX_SPANS):
+        del kernel_spans
         self.enabled = enabled
-        self.kernel_spans = kernel_spans
         self.max_spans = max_spans
         self.epoch = time.perf_counter()
         self.epoch_unix = time.time()
@@ -197,6 +229,27 @@ class Tracer:
             _CURRENT.reset(token)
             if s.t1 is None:
                 self.end(s)
+
+    def clock_anchor(self) -> None:
+        """Write this tracer's clock into the profiler's trace: a
+        ``tracer.clock`` annotation whose ``tracer_ns`` stat is the
+        tracer's reading (perf_counter ns since ``epoch``) at the
+        annotation's entry, the midpoint of readings taken just before
+        and just after it. The annotation's start in the trace is the same
+        instant on the profiler's clock, so their difference maps every
+        span onto the trace. Call it while the profiler records, once at
+        each end of the traced window: two anchors show the drift. Spans
+        themselves pay nothing for this."""
+        import jax
+        ann = jax.profiler.TraceAnnotation(CLOCK_ANCHOR)
+        epoch_ns = int(self.epoch * 1e9)
+        before = time.perf_counter_ns()
+        ann.__enter__()
+        after = time.perf_counter_ns()
+        try:
+            ann.set_metadata(tracer_ns=(before + after) // 2 - epoch_ns)
+        finally:
+            ann.__exit__(None, None, None)
 
     # -- reading / export --------------------------------------------------
     def spans(self) -> List[Span]:
@@ -309,29 +362,3 @@ def annotate(span: Optional[Span], **attrs: Any) -> None:
     tr = current_tracer()
     if tr is not None:
         tr.annotate(span, **attrs)
-
-
-def profiled_kernel(name: str, fn, *args, **kw):
-    """Wall-time profile one kernel call with block_until_ready fencing.
-
-    Only fires when (a) a tracer with ``kernel_spans`` is ambient and
-    (b) every operand is concrete — under a jit trace the call records
-    nothing (span timings of abstract tracers would measure *compile*
-    time and attach nothing meaningful). Inputs are fenced BEFORE the
-    span opens so pending async work upstream is not attributed to this
-    kernel, and the output is fenced before it closes so device time is
-    attributed instead of hidden in async dispatch.
-    """
-    cur = _CURRENT.get()
-    if cur is None or not (cur[0].enabled and cur[0].kernel_spans):
-        return fn(*args, **kw)
-    import jax
-    leaves = [a for a in args if hasattr(a, "shape")]
-    if any(isinstance(a, jax.core.Tracer) for a in leaves):
-        return fn(*args, **kw)
-    jax.block_until_ready(leaves)
-    shapes = [tuple(a.shape) for a in leaves[:3]]
-    with cur[0].span(name, "kernel", shapes=shapes):
-        out = fn(*args, **kw)
-        jax.block_until_ready(out)
-    return out

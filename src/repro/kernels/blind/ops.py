@@ -6,7 +6,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import tracing
 from repro.kernels.blind import ref
 from repro.kernels.blind.blind import blind_pallas, unblind_pallas
 
@@ -36,16 +35,12 @@ def _unblind_jit(y, u, k_out_bits: int, out_dtype=jnp.float32,
 
 
 def blind(x, r, k_bits: int, impl: str = "auto"):
-    """Profiling wrapper (``kernel.blind_encode`` spans, core/tracing.py):
-    fenced wall-time when a tracer with kernel spans is ambient and the
-    operands are concrete; the plain jitted call otherwise."""
-    return tracing.profiled_kernel("kernel.blind_encode", _blind_jit,
-                                   x, r, k_bits=k_bits, impl=impl)
+    """(quantize(x) + r) mod p, the blinded operand the device sees."""
+    return _blind_jit(x, r, k_bits=k_bits, impl=impl)
 
 
 def unblind(y, u, k_out_bits: int, out_dtype=jnp.float32,
             impl: str = "auto"):
-    """Profiling wrapper (``kernel.unblind`` spans)."""
-    return tracing.profiled_kernel("kernel.unblind", _unblind_jit,
-                                   y, u, k_out_bits=k_out_bits,
-                                   out_dtype=out_dtype, impl=impl)
+    """dequantize((y - u) mod p), the enclave's unblind."""
+    return _unblind_jit(y, u, k_out_bits=k_out_bits, out_dtype=out_dtype,
+                        impl=impl)

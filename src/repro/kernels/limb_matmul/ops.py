@@ -20,7 +20,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import tracing
 from repro.kernels.blind.blind import blind_encode_pallas
 from repro.kernels.limb_matmul import ref
 from repro.kernels.limb_matmul.limb_matmul import (limb_matmul_planes,
@@ -188,28 +187,23 @@ def _field_fold_jit(x_field, s_field, *, impl: str = "auto", bm=256, bk=1024):
 
 def field_matmul(x_field, w_field, **kw):
     """(X @ W) mod p. x: (M, K) int32 in [0, p); w: (K, N) int32 in [0, p).
-
-    Thin profiling wrapper over the jitted kernel: when a tracer with
-    kernel spans is ambient (core/tracing.py) and the operands are
-    concrete, the call is fenced with ``block_until_ready`` on both sides
-    and recorded as a ``kernel.limb_matmul`` span; otherwise it is the
-    jitted call, untouched."""
-    return tracing.profiled_kernel("kernel.limb_matmul", _field_matmul_jit,
-                                   x_field, w_field, **kw)
+    Its device phase is the caller's (``tier1.matmul`` on the request
+    path, ``factors.unblind`` / ``factors.fold`` for session factors)."""
+    return _field_matmul_jit(x_field, w_field, **kw)
 
 
 def fused_blinded_matmul(x, r, w_limbs, u, inv_scale, out_scale, **kw):
-    """Profiling wrapper over the fused chain (``kernel.fused_blind_matmul``
-    spans cover blind_encode + limb matmul + in-register unblind)."""
-    return tracing.profiled_kernel("kernel.fused_blind_matmul",
-                                   _fused_blinded_matmul_jit, x, r, w_limbs,
-                                   u, inv_scale, out_scale, **kw)
+    """The fused chain: blind_encode + limb matmul + in-register unblind.
+    One jitted program, under its caller's device phase (``tier1.matmul``):
+    a scope inside it would rename its kernels in the device trace, which
+    reads them by this program's name."""
+    return _fused_blinded_matmul_jit(x, r, w_limbs, u, inv_scale, out_scale,
+                                     **kw)
 
 
 def field_fold(x_field, s_field, **kw):
-    """Profiling wrapper over the jitted Freivalds fold (``kernel.fold``)."""
-    return tracing.profiled_kernel("kernel.fold", _field_fold_jit,
-                                   x_field, s_field, **kw)
+    """The Freivalds fold (X @ S) mod p for a skinny S."""
+    return _field_fold_jit(x_field, s_field, **kw)
 
 
 def blinded_matmul(x_blinded, w_field, **kw):

@@ -177,7 +177,7 @@ def run_engine(args) -> None:
     tracer = None
     if args.trace_out:
         from repro.core.tracing import Tracer
-        tracer = Tracer(kernel_spans=args.trace_kernels)
+        tracer = Tracer()
     engine = ServingEngine(
         EngineConfig(max_batch=args.batch, max_wait_ms=args.max_wait_ms,
                      compile_cache_dir=args.compile_cache_dir,
@@ -430,7 +430,7 @@ def run_chaos(args) -> None:
     tracer = None
     if args.trace_out:
         from repro.core.tracing import Tracer
-        tracer = Tracer(kernel_spans=args.trace_kernels)
+        tracer = Tracer()
     engine = ServingEngine(EngineConfig(max_batch=per, max_wait_ms=50.0),
                            tracer=tracer, recorder=_flight_recorder(args))
     engine.register_model(name, cfg, params, mode=args.mode,
@@ -631,11 +631,6 @@ def main():
                          "bundles (last spans + metric deltas + engine "
                          "events) on quarantine/breaker-open/degradation/"
                          "verify-failure. Requires --engine.")
-    ap.add_argument("--trace-kernels", action="store_true",
-                    help="with --trace-out, also record fenced wall-time "
-                         "kernel spans (blind_encode/limb_matmul/fold) — "
-                         "adds block_until_ready fences, so only for "
-                         "profiling runs")
     args = ap.parse_args()
     if args.devices and not args.engine:
         ap.error("--devices requires --engine")

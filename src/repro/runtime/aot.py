@@ -70,11 +70,21 @@ def use_persistent_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
     used as it stands; otherwise the cache lives at the fixed
     ``<checkout>/.jax_cache``, so a later run finds what an earlier one
-    compiled. Returns the directory in use."""
+    compiled. Returns the directory in use.
+
+    A cached executable keeps the op metadata it was compiled with, and
+    the profiler's trace reads the program's device phases from it
+    (core/tracing.py ``DEVICE_PHASES``), so the cache key includes the
+    metadata: by default JAX leaves it out and would hand a program the
+    executable of one that differs only in its name scopes. Op locations
+    then carry the name scopes and no source frames, so the key does not
+    depend on where the checkout lives or on line numbers."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(_CHECKOUT / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     return path
 
 

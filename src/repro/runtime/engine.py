@@ -562,7 +562,8 @@ class ServingEngine:
                                       executor, "attested_digest",
                                       executor.plan.digest)),
             pool=pool or SessionPool(executor,
-                                     depth=self.cfg.session_pool_depth),
+                                     depth=self.cfg.session_pool_depth,
+                                     tracer=self.tracer),
             plan=plan, placement=executor.plan,
             input_key=input_key, input_dtype=input_dtype)
         entry.chaos = chaos

@@ -64,11 +64,7 @@ _NAME_PHASE = {
     "unseal": "unseal",
     "seal": "seal",
     "session.acquire": "blind",
-    "kernel.blind_encode": "blind",
-    "kernel.fused_blind_matmul": "device_compute",
-    "kernel.limb_matmul": "device_compute",
-    "kernel.unblind": "unblind",
-    "kernel.fold": "verify",
+    "factors.sync": "blind",
     "op.blinded": "unblind",
     "op.trusted": "device_compute",
     "shard.matmul": "dispatch_wait",

@@ -34,7 +34,7 @@ from repro.configs.base import ModelConfig
 from repro.core import tracing
 from repro.core.attestation import Quote, measure_enclave, verify_quote
 from repro.core.origami import OrigamiExecutor
-from repro.core.sealing import SealedBox, seal, unseal
+from repro.core.sealing import SealedBox, open_response, seal, unseal
 from repro.runtime.aot import bucket_for
 from repro.runtime.straggler import StepWatchdog
 
@@ -405,7 +405,7 @@ class PrivateInferenceServer:
     @staticmethod
     def client_open(key: np.ndarray, box: SealedBox,
                     shape: Tuple[int, ...]) -> np.ndarray:
-        pt, ok = unseal(jnp.asarray(key, jnp.uint32), box, shape)
+        pt, ok = open_response(jnp.asarray(key, jnp.uint32), box, shape)
         assert bool(ok), "response MAC failed"
         return np.asarray(pt)
 

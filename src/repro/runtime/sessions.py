@@ -30,6 +30,11 @@ the request path again.
   synchronous factors for that session. ``refill_fault`` is the chaos
   harness's injection point (runtime/chaos.py): a callable run before
   each prefetch, so a drill can script exactly this failure mode.
+- **tracing**: with a ``tracer`` (core/tracing.py) every prefetch is one
+  ``factors.prefetch`` root span (``counter``, ``n_caches``; a failed one
+  carries ``error``, the exception's class name), and ``stats()`` sums
+  the caches' ``factors_taken`` / ``factors_sync``: the sets a batch took,
+  and those it had to make itself while the request waited.
 
 The pool is executor-agnostic: before the first batch builds the layer
 cache, ``prepare`` is a no-op and ``acquire`` simply hands out fresh keys
@@ -43,6 +48,8 @@ from typing import Callable, Dict, Optional, Set
 
 import jax
 import numpy as np
+
+from repro.core import tracing
 
 
 class SessionReuseError(RuntimeError):
@@ -71,10 +78,12 @@ class SessionPool:
     def __init__(self, executor=None, *, depth: int = 4,
                  root: Optional[jax.Array] = None,
                  background: bool = True,
-                 refill_fault: Optional[Callable[[int], None]] = None):
+                 refill_fault: Optional[Callable[[int], None]] = None,
+                 tracer: Optional[tracing.Tracer] = None):
         assert depth >= 1, depth
         self.executor = executor
         self.depth = depth
+        self.tracer = tracer
         # chaos hook: called with the session counter before each prefetch;
         # raising makes that refill fail exactly like a real one would
         self.refill_fault = refill_fault
@@ -141,6 +150,31 @@ class SessionPool:
             cache.prefetch(self._key_for(counter))
         return bool(caches)
 
+    def _refill(self, counter: int) -> None:
+        """One prefetch with its containment: a failure is counted (and
+        traced), never raised — a dead refill thread would silently put
+        every factor matmul back on the hot path; ``acquire`` falls back
+        to synchronous factors for this session."""
+        tr = self.tracer
+        sp = None
+        if tr is not None and tr.enabled:
+            # a root span on the refill thread: it has no ambient context
+            sp = tr.start_span("factors.prefetch", "session",
+                               counter=counter, n_caches=len(self._caches()))
+        try:
+            ok = self._prefetch(counter)
+        except Exception as e:  # noqa: BLE001 — see the docstring
+            with self._lock:
+                self.refill_errors += 1
+            if sp is not None:
+                tr.end(sp, error=type(e).__name__)
+            return
+        if sp is not None:
+            tr.end(sp)
+        if ok:
+            with self._lock:
+                self.refilled += 1
+
     def _refill_loop(self) -> None:
         while True:
             with self._cv:
@@ -155,18 +189,7 @@ class SessionPool:
                     return
                 counter = self._next
                 self._next += 1
-            try:
-                ok = self._prefetch(counter)
-            except Exception:  # noqa: BLE001 — a dead refill thread would
-                # silently put every factor matmul back on the hot path;
-                # count the failure and keep the loop alive (acquire()
-                # falls back to synchronous factors for this session)
-                with self._lock:
-                    self.refill_errors += 1
-                continue
-            if ok:
-                with self._lock:
-                    self.refilled += 1
+            self._refill(counter)
 
     # -- public API --------------------------------------------------------
     def acquire(self) -> jax.Array:
@@ -203,15 +226,7 @@ class SessionPool:
                                                 self._head + self.depth)
             stop = self._next
         for c in range(start, stop):
-            try:
-                ok = self._prefetch(c)
-            except Exception:  # noqa: BLE001 — same containment as the loop
-                with self._lock:
-                    self.refill_errors += 1
-                continue
-            if ok:
-                with self._lock:
-                    self.refilled += 1
+            self._refill(c)
 
     def ready(self) -> int:
         """How many handed-out-next sessions have factors prefetched."""
@@ -224,11 +239,16 @@ class SessionPool:
                    for c in range(head, nxt))
 
     def stats(self) -> Dict[str, int]:
+        caches = self._caches()
         with self._lock:
             return {"consumed": self.consumed, "refilled": self.refilled,
                     "misses": self.misses, "reuse_checked": self.reuse_checked,
                     "refill_errors": self.refill_errors,
-                    "depth": self.depth, "pending": self._next - self._head}
+                    "depth": self.depth, "pending": self._next - self._head,
+                    "factors_taken": sum(getattr(c, "factors_taken", 0)
+                                         for c in caches),
+                    "factors_sync": sum(getattr(c, "factors_sync", 0)
+                                        for c in caches)}
 
     def close(self) -> None:
         with self._cv:

@@ -37,10 +37,15 @@ def _request(cfg, rid, rng):
 def test_persistent_compile_cache_dir(monkeypatch, tmp_path, env_dir):
     """JAX_COMPILATION_CACHE_DIR, when set, is used as it stands and the
     helper sets no other directory; unset, the cache lives at the fixed
-    <checkout>/.jax_cache — never a temp-, pid- or time-derived path."""
+    <checkout>/.jax_cache — never a temp-, pid- or time-derived path.
+    Either way the key includes the op metadata (the device phases' name
+    scopes), and locations carry no source frames."""
     from pathlib import Path
     from repro.runtime.aot import use_persistent_compile_cache
     before = jax.config.jax_compilation_cache_dir
+    flags = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_traceback_in_locations_limit")
+    kept = {f: getattr(jax.config, f) for f in flags}
     if env_dir is None:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     else:
@@ -56,8 +61,12 @@ def test_persistent_compile_cache_dir(monkeypatch, tmp_path, env_dir):
         else:
             assert path == str(tmp_path / env_dir)
             assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert jax.config.jax_traceback_in_locations_limit == 0
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        for f, v in kept.items():
+            jax.config.update(f, v)
 
 
 def test_bucket_ladder_powers_of_two():

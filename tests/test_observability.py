@@ -14,6 +14,7 @@ from repro.configs import get_smoke
 from repro.core import plan as PL
 from repro.core import tracing
 from repro.core.integrity import IntegrityPolicy
+from repro.core.origami import OrigamiExecutor
 from repro.core.tracing import RedactionError, Tracer, redact
 from repro.models import model as M
 from repro.runtime.devices import DevicePool
@@ -128,25 +129,181 @@ def test_span_attach_fails_closed():
     tr.end(s)
 
 
-def test_profiled_kernel_records_only_when_concrete():
-    from repro.kernels.limb_matmul.ops import field_matmul
+# -- device phases and the session factors --------------------------------
+
+def _hlo(jitted, *args, **kw) -> str:
+    return jitted.lower(*args, **kw).compile().as_text()
+
+
+def _op_names(hlo: str):
+    import re
+    return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+
+def _phases_in(hlo: str):
+    from repro.core.tracing import DEVICE_PHASES
+    return {part for name in _op_names(hlo) for part in name.split("/")
+            if part in DEVICE_PHASES}
+
+
+def test_device_phase_scopes_in_lowered_hlo():
+    """Every device phase is a name scope the compiled HLO carries in its
+    op metadata: the sealing cores each under their own (the client's open
+    apart from the enclave's unseal, one body), a blinded executor's trace
+    under the tier-1 and tier-2 phases and, deriving its factors live,
+    the factor phases — which the precompute cache's per-layer program
+    carries too."""
+    from repro.core import sealing as S
+    from repro.core.precompute import _layer_factors
+    from repro.core.tracing import DEVICE_PHASES
     from repro.kernels.limb_matmul.ref import P
-    tr = Tracer()                            # kernel_spans on by default
-    x = jnp.asarray(np.random.default_rng(0).integers(
-        0, P, (8, 8), dtype=np.int32))
-    w = jnp.asarray(np.random.default_rng(1).integers(
-        0, P, (8, 8), dtype=np.int32))
-    with tr.span("request", "request"):
-        field_matmul(x, w)
-        jax.jit(lambda a, b: field_matmul(a, b))(x, w)  # traced: no span
-    kernels = [s for s in tr.spans() if s.kind == "kernel"]
-    assert [s.name for s in kernels] == ["kernel.limb_matmul"]
-    assert kernels[0].attrs["shapes"] == [[8, 8], [8, 8]]
-    assert kernels[0].t1 is not None
-    # no ambient tracer: plain call, nothing recorded anywhere
-    before = len(tr.spans())
-    field_matmul(x, w)
-    assert len(tr.spans()) == before
+    key = jnp.zeros((2,), jnp.uint32)
+    ct = jnp.zeros((3, 4), jnp.uint32)
+    nonce = jnp.zeros((2,), jnp.uint32)
+    assert _phases_in(_hlo(S._unseal_core, key, ct, nonce)) == {
+        "enclave.unseal"}
+    assert _phases_in(_hlo(S._open_core, key, ct, nonce)) == {"client.open"}
+    assert _phases_in(_hlo(S._seal_core, key, jnp.zeros((5,)), nonce)) == {
+        "enclave.seal"}
+    cfg = get_smoke("vgg16")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    found = set()
+    for impl in ("fused", "unfused"):
+        ex = OrigamiExecutor(cfg, params, impl=impl,
+                             integrity=IntegrityPolicy.full(1))
+        x = jnp.zeros((2, cfg.image_size, cfg.image_size, 3), jnp.float32)
+        found |= _phases_in(_hlo(ex._aot_jit, params, {"images": x},
+                                 jax.random.PRNGKey(1), None))
+    assert found == set(DEVICE_PHASES) - {"enclave.unseal", "client.open",
+                                          "enclave.seal"}
+    w = jnp.asarray(np.random.default_rng(0).integers(0, P, (16, 8),
+                                                      dtype=np.int32))
+    assert _phases_in(_hlo(
+        _layer_factors, jax.random.PRNGKey(2), 0, w, layer=0, t=4, d_in=16,
+        d_out=8, pad=True, k=1, shards=2)) == {
+        "factors.pad", "factors.unblind", "factors.fold"}
+
+
+def test_sealing_programs_agree_bit_for_bit():
+    """The client's open and the enclave's unseal are one body: the same
+    words and the same tag, and a tampered box fails both."""
+    from repro.core import sealing as S
+    key = jnp.asarray([7, 9], jnp.uint32)
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(6, 5)), jnp.float32)
+    box = S.seal(key, x, jnp.asarray([1, 2, 3], jnp.uint32))
+    pt_e, ok_e = S.unseal(key, box, (6, 5))
+    pt_c, ok_c = S.open_response(key, box, (6, 5))
+    assert ok_e and ok_c
+    np.testing.assert_array_equal(np.asarray(pt_e), np.asarray(x))
+    np.testing.assert_array_equal(np.asarray(pt_c), np.asarray(x))
+    bad = box._replace(mac=box.mac ^ jnp.uint32(1))
+    assert not S.unseal(key, bad, (6, 5))[1]
+    assert not S.open_response(key, bad, (6, 5))[1]
+
+
+def _pooled_executor():
+    from repro.core.origami import OrigamiExecutor
+    cfg = get_smoke("vgg16")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    ex = OrigamiExecutor(cfg, params, precompute=True,
+                         integrity=IntegrityPolicy.full(1))
+    batch = {"images": jnp.zeros((2, cfg.image_size, cfg.image_size, 3),
+                                 jnp.float32)}
+    ex.build_cache(batch)
+    return ex, batch
+
+
+def test_take_counts_sets_made_on_the_request_path():
+    """``take`` counts every set a request takes, and apart those it had
+    to make itself (a ``factors.sync`` span under the request's tracer):
+    one for a key the pool never prefetched, none for one it did. The
+    pool's ``stats`` sums them over the executor's caches."""
+    from repro.runtime.sessions import SessionPool
+    ex, _ = _pooled_executor()
+    tr = Tracer()
+    pool = SessionPool(ex, depth=2, background=False, tracer=tr)
+    cold = pool.acquire()                 # nothing prefetched yet
+    with tr.span("infer", "infer"), tracing.activate(tr, tr.spans()[-1]):
+        ex.cache.take(cold)
+    assert (ex.cache.factors_taken, ex.cache.factors_sync) == (1, 1)
+    sync, = [s for s in tr.spans() if s.name == "factors.sync"]
+    assert sync.kind == "session" and sync.parent_id is not None
+    assert sync.attrs == {"layers": len(ex.cache.layers), "bucket": 2}
+    pool.prime()
+    warm = pool.acquire()
+    assert ex.cache.prefetched(warm)
+    ex.cache.take(warm)
+    assert (ex.cache.factors_taken, ex.cache.factors_sync) == (2, 1)
+    st = pool.stats()
+    assert (st["factors_taken"], st["factors_sync"]) == (2, 1)
+    assert st["misses"] == 1              # the acquire-time count stays
+
+
+def test_refill_failures_are_traced_not_raised():
+    """A prefetch that raises is counted (``refill_errors``) and recorded
+    as a ``factors.prefetch`` root span carrying the exception's class
+    name; a good one as the same span without it."""
+    from repro.runtime.sessions import SessionPool
+    ex, _ = _pooled_executor()
+    tr = Tracer()
+
+    def fault(counter):
+        if counter == 0:
+            raise KeyError("refill fault")
+
+    pool = SessionPool(ex, depth=2, background=False, tracer=tr,
+                       refill_fault=fault)
+    pool.prime()
+    spans = [s for s in tr.spans() if s.name == "factors.prefetch"]
+    assert [s.attrs.get("counter") for s in spans] == [0, 1]
+    assert spans[0].attrs["error"] == "KeyError"
+    assert "error" not in spans[1].attrs
+    assert all(s.parent_id is None and s.t1 is not None and
+               s.attrs["n_caches"] == 1 for s in spans)
+    assert pool.stats()["refill_errors"] == 1
+    assert pool.stats()["refilled"] == 1
+
+
+def test_refill_thread_traces_each_prefetch():
+    """Under an engine with a tracer, the session pool's refill thread
+    records one ``factors.prefetch`` root span per session it prefetches,
+    and the batch that finds its factors prefetched records no
+    ``factors.sync``."""
+    import time
+    cfg = get_smoke("vgg16")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    tracer = Tracer()
+    engine = ServingEngine(EngineConfig(max_batch=1, max_wait_ms=1.0,
+                                        session_pool_depth=2),
+                           tracer=tracer)
+    try:
+        entry = engine.register_model("vgg16", cfg, params, mode="origami")
+        img = np.zeros((cfg.image_size, cfg.image_size, 3), np.float32)
+        key = np.array([1, 2], dtype=np.uint32)
+        for rid in (1, 2):
+            box = PrivateInferenceServer.client_seal(key, img, rid)
+            resp = engine.submit("vgg16", Request(
+                rid=rid, box=box, shape=img.shape,
+                session_key=key)).result(timeout=300)
+            assert resp.ok, resp.error
+            t_end = time.monotonic() + 60
+            while (entry.pool.stats()["pending"] < 2
+                   and time.monotonic() < t_end):
+                time.sleep(0.02)
+            time.sleep(0.2)
+    finally:
+        engine.close()
+    spans = tracer.spans()
+    prefetch = [s for s in spans if s.name == "factors.prefetch"]
+    assert len(prefetch) >= 2
+    assert all(s.parent_id is None and s.kind == "session"
+               and {"counter", "n_caches"} <= set(s.attrs)
+               for s in prefetch)
+    assert {s.tid for s in prefetch} != {threading.get_ident()}
+    st = entry.pool.stats()
+    assert st["factors_taken"] == 2
+    assert st["factors_sync"] == 1       # the first batch, before the pool
+    assert len([s for s in spans if s.name == "factors.sync"]) == 1
 
 
 # -- the acceptance run: one traced request, mixed + verified + sharded ----
@@ -162,7 +319,7 @@ def traced_run():
     unseal."""
     cfg = get_smoke("vgg16")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
-    tracer = Tracer()                        # kernel spans on
+    tracer = Tracer()
     engine = ServingEngine(EngineConfig(max_batch=2, max_wait_ms=20.0),
                            tracer=tracer)
     entry = engine.register_model(
@@ -199,8 +356,13 @@ def traced_run():
 
 def test_span_tree_connected_and_complete(traced_run):
     tr = traced_run["tracer"]
-    spans = tr.spans()
-    roots = tr.roots()
+    # the session pool's refill thread records its prefetches as roots of
+    # their own (no request context on that thread)
+    prefetch = [s for s in tr.spans() if s.name == "factors.prefetch"]
+    assert all(s.parent_id is None and s.kind == "session"
+               for s in prefetch)
+    spans = [s for s in tr.spans() if s.name != "factors.prefetch"]
+    roots = [s for s in tr.roots() if s.name != "factors.prefetch"]
     assert len(roots) == 1 and roots[0].name == "request"
     root = roots[0]
     # every span connects to the single request root (one trace, one tree)
@@ -219,8 +381,7 @@ def test_span_tree_connected_and_complete(traced_run):
     names = {s.name for s in spans}
     required = {"request", "queue", "batch", "unseal", "session.acquire",
                 "infer", "plan.segment", "op.blinded", "shard.matmul",
-                "shard.dispatch", "verify", "seal",
-                "kernel.limb_matmul", "kernel.fold"}
+                "shard.dispatch", "verify", "seal", "factors.sync"}
     assert required <= names, f"missing spans: {required - names}"
     # the dishonest device forces a failed attempt and a retry dispatch
     dispatches = [s for s in spans if s.name == "shard.dispatch"]

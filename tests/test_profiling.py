@@ -59,11 +59,10 @@ def _tree(rid=1, model="m", plan="abc", t0=0.0, infer_dur=1.0,
 
 def test_phase_taxonomy_is_total():
     for name in ("queue", "unseal", "seal", "session.acquire",
-                 "kernel.blind_encode", "kernel.fused_blind_matmul",
-                 "kernel.limb_matmul", "kernel.unblind", "kernel.fold",
-                 "op.blinded", "op.trusted", "shard.matmul",
-                 "shard.dispatch", "shard.enclave", "infer",
-                 "plan.segment", "verify", "batch", "request"):
+                 "factors.sync", "factors.prefetch", "op.blinded",
+                 "op.trusted", "shard.matmul", "shard.dispatch",
+                 "shard.enclave", "infer", "plan.segment", "verify",
+                 "batch", "request"):
         assert phase_of(name) in PHASES
     assert phase_of("some.future.span") == "other"   # never drops time
 
@@ -247,7 +246,7 @@ def quarantine_bundle(tmp_path_factory):
     out_dir = tmp_path_factory.mktemp("postmortem")
     cfg = get_smoke("vgg16")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
-    tracer = Tracer(kernel_spans=False)
+    tracer = Tracer()
     rec = FlightRecorder(out_dir=str(out_dir), min_interval_s=0.0)
     engine = ServingEngine(
         EngineConfig(max_batch=2, max_wait_ms=20.0, quarantine_after=1),

@@ -87,3 +87,38 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, M, K, N):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < HBM_BYTES, used
+
+
+def test_kernels_keep_their_program_names_under_device_phases(one_chip):
+    """A device phase scope at the call site rides in each kernel's
+    ``op_name`` and leaves the kernel's instruction name in the compiled
+    HLO (what the device trace names it by) that of its jitted program."""
+    import re
+
+    from repro.core import tracing
+    M, K, N = 512, 576, 128
+    _, _, _, _, Kp, Np = ops.block_plan(M, K, N)
+    a = functools.partial(_sds, one_chip)
+
+    def tier1(x, r, w_limbs, u, w_field):
+        with jax.named_scope(tracing.TIER1_MATMUL):
+            y = ops.fused_blinded_matmul(x, r, w_limbs, u, 1.0, 1.0,
+                                         k_bits=8, k_out_bits=16,
+                                         impl="pallas")
+        with jax.named_scope(tracing.FACTORS_UNBLIND):
+            return y, ops.field_matmul(r, w_field, impl="pallas")
+
+    text = jax.jit(tier1).lower(
+        a((M, K), jnp.float32), a((M, K), jnp.int32),
+        a((3, Kp, Np), jnp.int8), a((M, N), jnp.int32),
+        a((K, N), jnp.int32)).compile().as_text()
+    calls = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*tpu_custom_call[^\n]*op_name=\"([^\"]*)\"",
+        text)]
+    names = sorted(n.rsplit(".", 1)[0] for n, _ in calls)
+    assert names == ["_field_matmul_jit", "_fused_blinded_matmul_jit",
+                     "_fused_blinded_matmul_jit"], calls
+    for name, op_name in calls:
+        phase = (tracing.FACTORS_UNBLIND if name.startswith("_field")
+                 else tracing.TIER1_MATMUL)
+        assert f"/{phase}/" in op_name, (name, op_name)
