@@ -13,12 +13,16 @@ network attacker a timing oracle over the tag (and the jnp comparison it
 replaced also forced a device sync per word).
 
 The cipher/MAC arithmetic is jitted (``_seal_core`` / ``_unseal_core``):
-the keystream derivation and the per-word MAC scan are pure integer ops
-whose eager dispatch used to cost ~100 ms per 3 K-word request — two
-orders of magnitude more than the compiled loop, for bit-identical words.
+the keystream derivation and the MAC are pure integer ops. The MAC's
+polynomial is evaluated in closed form, data-parallel over the words (one
+power per word, one sum), not by Horner's rule one word per loop trip: a
+serial loop pays a fixed cost per trip on a TPU (about 1.4 us on a v5e,
+0.2 s for a 224x224x3 request). The tag is bit-identical to Horner's
+(see ``_mac``).
 Only the trust-boundary tag compare stays on the host (a Python bool from
-``hmac.compare_digest``), so the security posture is unchanged. Each core
-runs under its device phase (core/tracing.py ``DEVICE_PHASES``).
+``hmac.compare_digest``, constant-time), so the security posture is
+unchanged. Each core runs under its device phase (core/tracing.py
+``DEVICE_PHASES``).
 """
 from __future__ import annotations
 
@@ -49,17 +53,33 @@ def _keystream(key: jax.Array, nonce: jax.Array, n: int) -> jax.Array:
 
 
 def _mac(key: jax.Array, data_u32: jax.Array) -> jax.Array:
-    """Carter-Wegman-style polynomial MAC over u32 words (mod 2^32)."""
+    """Carter-Wegman-style polynomial MAC over u32 words (mod 2^32).
+
+    The tag is the value of Horner's recurrence ``acc <- acc*c0 + w_i + c1``
+    from ``acc = IV`` over the N words, written in closed form:
+
+        tag = c0^N * IV + sum_{i<N} (w_i + c1) * c0^(N-1-i)   (mod 2^32)
+
+    uint32 multiply and add wrap mod 2^32, and sums in that ring do not
+    depend on the order of reduction, so this equals the recurrence bit
+    for bit. The powers come from square-and-multiply over the exponents
+    N-1-i, ceil(log2 N) elementwise steps with no loop over the words.
+    """
     k = jax.random.fold_in(jax.random.wrap_key_data(
         jnp.asarray(key, jnp.uint32)), jnp.uint32(0xA11CE))
     coeff = jax.random.bits(k, (2,), jnp.uint32)
     c0 = coeff[0] | jnp.uint32(1)      # odd => unit mod 2^32 (invertible)
 
-    def step(acc, w):
-        return acc * c0 + w + coeff[1], None
-
-    acc, _ = jax.lax.scan(step, jnp.uint32(0x9E3779B9), data_u32)
-    return acc
+    n = data_u32.shape[0]
+    exps = jnp.arange(n - 1, -1, -1, dtype=jnp.uint32)   # N-1-i
+    pows = jnp.ones((n,), jnp.uint32)
+    base = c0
+    for b in range((n - 1).bit_length()):
+        pows = jnp.where((exps >> b) & 1, pows * base, pows)
+        base = base * base
+    # pows[0] is c0^(N-1)
+    return (pows[0] * c0 * jnp.uint32(0x9E3779B9)
+            + jnp.sum((data_u32 + coeff[1]) * pows, dtype=jnp.uint32))
 
 
 def _authenticated_words(nonce: jax.Array, ct: jax.Array) -> jax.Array:
